@@ -22,6 +22,7 @@ from typing import Dict, List
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import FORECASTER_KINDS, EngineConfig
 from repro.dsp import (PeriodicFailures, RunResult, run_experiment, run_sweep,
                        scenario_grid, make_trace, tsw_like, ysb_like,
@@ -300,6 +301,7 @@ def main() -> None:
     pp.set_defaults(func=paper_main)
 
     args = ap.parse_args()
+    enable_compile_cache()
     args.func(args)
 
 

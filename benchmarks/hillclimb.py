@@ -81,7 +81,8 @@ def run(cell: str, change: str, out: str = "results/perf_iterations.json"
 
 
 def summarize(out: str = "results/perf_iterations.json") -> None:
-    from .roofline import HBM_BW, ICI_BW, PEAK_FLOPS
+    from .roofline import DRYRUN_DEVICE_KIND, chip_peaks
+    peaks = chip_peaks(DRYRUN_DEVICE_KIND)
     with open(out) as f:
         results = json.load(f)
     print(f"{'cell@change':58s} {'compute_s':>9s} {'memory_s':>9s} "
@@ -92,9 +93,9 @@ def summarize(out: str = "results/perf_iterations.json") -> None:
             print(f"{key:58s} {r.get('status')}: "
                   f"{str(r.get('error'))[:60]}")
             continue
-        c = r["flops"] / PEAK_FLOPS
-        m = r["bytes_accessed"] / HBM_BW
-        k = r["collective_total"] / ICI_BW
+        c = r["flops"] / peaks.flops
+        m = r["bytes_accessed"] / peaks.hbm_bw
+        k = r["collective_total"] / peaks.ici_bw
         print(f"{key:58s} {c:9.4f} {m:9.4f} {k:9.4f} {max(c, m, k):9.4f}")
 
 

@@ -1,15 +1,17 @@
 """§Roofline: derive the three-term roofline per (arch x shape) cell.
 
 Sources: the unrolled single-pod dry-run (results/roofline_raw.json) for
-exact per-device HLO FLOPs / bytes / collective bytes. Hardware: TPU v5e —
-197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI (assignment constants).
+exact per-device HLO FLOPs / bytes / collective bytes. Hardware peaks come
+from :data:`CHIP_PEAKS`, keyed by ``jax.Device.device_kind`` with their
+published source; a kind missing from the table is an error, never a
+default. The dry-run models a TPU v5e pod (:data:`DRYRUN_DEVICE_KIND`).
 
 cost_analysis of the SPMD-partitioned module reports per-device numbers
 (validated against 6·N·D in tests), so terms are directly:
 
-    compute_s    = flops / 197e12
-    memory_s     = bytes_accessed / 819e9
-    collective_s = collective_bytes / 50e9
+    compute_s    = flops / peak_flops
+    memory_s     = bytes_accessed / hbm_bw
+    collective_s = collective_bytes / ici_bw
 
 A second section reads the sweep-engine legs from the schema-versioned
 bench trajectory (``BENCH_sweep.json``, written by
@@ -39,10 +41,40 @@ from repro.launch.specs import SHAPES, SHAPE_KIND
 from repro.models import param_count
 from repro.models.config import ModelConfig
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
 CHIPS = 256
+
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks."""
+
+    flops: float        # dense bf16 FLOP/s
+    hbm_bw: float       # HBM bytes/s
+    ici_bw: float       # interchip bytes/s per link
+    source: str
+
+
+#: Per-chip peaks keyed by ``jax.Device.device_kind``.
+CHIP_PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s interchip interconnect "
+               "(200 GB/s over 4 links = 50 GB/s per link)"),
+}
+
+#: The chip whose pod the dry-run's production mesh models.
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of ``device_kind``; raises for a kind with no published row."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(CHIP_PEAKS)}"
+                         ) from None
 
 
 def active_param_count(cfg: ModelConfig) -> int:
@@ -82,6 +114,7 @@ class RooflineCell:
     collective_s: float
     model_flops: float
     hlo_flops: float
+    peak_flops: float
 
     @property
     def dominant(self) -> str:
@@ -101,12 +134,15 @@ class RooflineCell:
     def roofline_fraction(self) -> float:
         """Fraction of peak sustained if the step runs at the dominant
         bound: MODEL_FLOPS / (step_s · PEAK)."""
-        return self.model_flops / (self.step_s * PEAK_FLOPS) \
+        return self.model_flops / (self.step_s * self.peak_flops) \
             if self.step_s else 0.0
 
 
 def load_cells(path: str = "results/roofline_raw.json",
-               mesh: str = "single") -> Dict[str, RooflineCell]:
+               mesh: str = "single",
+               device_kind: str = DRYRUN_DEVICE_KIND
+               ) -> Dict[str, RooflineCell]:
+    peaks = chip_peaks(device_kind)
     with open(path) as f:
         raw = json.load(f)
     cells = {}
@@ -116,11 +152,12 @@ def load_cells(path: str = "results/roofline_raw.json",
         cfg = get_config(rec["arch"])
         cell = RooflineCell(
             arch=rec["arch"], shape=rec["shape"],
-            compute_s=rec["flops"] / PEAK_FLOPS,
-            memory_s=rec["bytes_accessed"] / HBM_BW,
-            collective_s=rec["collective_total"] / ICI_BW,
+            compute_s=rec["flops"] / peaks.flops,
+            memory_s=rec["bytes_accessed"] / peaks.hbm_bw,
+            collective_s=rec["collective_total"] / peaks.ici_bw,
             model_flops=model_flops_per_device(cfg, rec["shape"]),
             hlo_flops=rec["flops"],
+            peak_flops=peaks.flops,
         )
         cells[f"{rec['arch']}/{rec['shape']}"] = cell
     return cells

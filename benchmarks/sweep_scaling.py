@@ -1,11 +1,18 @@
 """Weak/strong-scaling benchmark for the device-sharded sweep engine.
 
 Measures per-step sweep throughput (scenario-steps/s) as a function of the
-``scenario``-mesh width. XLA latches the device count at backend init, so
-the parent process re-launches itself once per requested count with
-``--xla_force_host_platform_device_count=N`` injected into ``XLA_FLAGS`` —
-the whole benchmark runs on a single CPU host (or on real accelerators by
-just not forcing the flag):
+``scenario``-mesh width. How the legs run depends on the platform, decided
+from ``JAX_PLATFORMS`` before anything touches a device:
+
+* ``JAX_PLATFORMS=cpu`` — XLA latches the host device count at backend
+  init, so the parent (which never initializes JAX) re-launches itself
+  once per requested count with ``--xla_force_host_platform_device_count=N``
+  injected into ``XLA_FLAGS``;
+* anything else (an accelerator host) — every leg runs in this one
+  process over ``jax.devices()[:N]``: a chip belongs to one process, so a
+  child could not reach it while the parent holds it.
+
+Modes:
 
 * **strong scaling** — a fixed grid of ``--scenarios`` cells split over
   1/2/4 devices;
@@ -62,10 +69,8 @@ CONTROLLERS = ("static", "reactive")
 def device_env(n_devices: int) -> Dict[str, str]:
     """This process's environment with ``n_devices`` virtual host devices
     and ``src/`` importable in the child."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    src = os.path.join(repo, "src")
-    if src not in sys.path:              # parent may run without PYTHONPATH
-        sys.path.insert(0, src)
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
     from repro.distributed.mesh import force_host_device_flags
     env = os.environ.copy()
     env["XLA_FLAGS"] = force_host_device_flags(env.get("XLA_FLAGS", ""),
@@ -85,41 +90,60 @@ def build_grid(n_scenarios: int, duration_s: float, dt_s: float):
     return grid[:n_scenarios]
 
 
-def child_main(args: argparse.Namespace) -> None:
-    """One measurement leg: runs inside the forced-device-count process."""
-    import jax
-
+def measure_leg(devices: int, scenarios: int, duration_h: float, dt: float,
+                engine: str = "auto") -> dict:
+    """One measurement leg on a ``devices``-wide scenario mesh taken from
+    the first ``devices`` visible devices."""
     from repro.core import EngineConfig
     from repro.dsp import run_sweep
 
-    n = args.devices
-    assert jax.device_count() == n, \
-        f"backend has {jax.device_count()} devices, expected {n}"
-    engine = args.engine
     if engine == "auto":
-        engine = "sharded" if n > 1 else "batched"
-    config = EngineConfig(sim_backend=engine,
-                          devices=n if n > 1 else None)
-    grid = build_grid(args.scenarios, args.duration_h * 3600.0, args.dt)
+        engine = "sharded" if devices > 1 else "batched"
+    # Explicit width: devices=None would take every visible device.
+    config = EngineConfig(sim_backend=engine, devices=devices)
+    grid = build_grid(scenarios, duration_h * 3600.0, dt)
     # Warm the jit cache (the sharded step compiles per grid shape), so the
     # measured leg reports steady-state per-step throughput.
-    run_sweep(build_grid(args.scenarios, 10 * args.dt, args.dt),
-              config=config)
+    run_sweep(build_grid(scenarios, 10 * dt, dt), config=config)
     t0 = time.perf_counter()
     res = run_sweep(grid, config=config)
     wall = time.perf_counter() - t0
-    record = {
-        "devices": n, "engine": engine, "seed": 0,
+    return {
+        "devices": devices, "engine": engine, "seed": 0,
         "scenarios": len(grid),
         "n_steps": res.n_steps, "wall_s": wall,
         "sweep_wall_s": res.wall_s,
         "scenario_steps_per_s": len(grid) * res.n_steps / res.wall_s,
     }
+
+
+def child_main(args: argparse.Namespace) -> None:
+    """One leg inside a forced-device-count CPU process."""
+    import jax
+
+    n = args.devices
+    if jax.device_count() != n:
+        sys.exit(f"backend has {jax.device_count()} devices, expected {n}")
+    record = measure_leg(n, args.scenarios, args.duration_h, args.dt,
+                         args.engine)
     print("RESULT " + json.dumps(record), flush=True)
+
+
+def forced_host_devices() -> bool:
+    """True when legs need forced-device-count CPU children."""
+    return os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
 
 
 def run_leg(devices: int, scenarios: int, args: argparse.Namespace,
             engine: str = "auto") -> Optional[dict]:
+    if not forced_host_devices():
+        try:
+            return measure_leg(devices, scenarios, args.duration_h, args.dt,
+                               engine)
+        except ValueError as e:         # e.g. more devices than visible
+            print(f"# leg devices={devices} engine={engine} FAILED: {e}",
+                  file=sys.stderr)
+            return None
     cmd = [sys.executable, os.path.abspath(__file__), "--child",
            "--devices", str(devices), "--scenarios", str(scenarios),
            "--duration-h", str(args.duration_h), "--dt", str(args.dt),
@@ -199,6 +223,12 @@ def main() -> None:
         child_main(args)
         return
 
+    # src/ on sys.path for in-process legs and the bench merge; repro.obs
+    # imports no jax, so a forced-device parent never initializes a backend.
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    if src not in sys.path:
+        sys.path.insert(0, src)
     counts = [int(c) for c in args.device_counts.split(",") if c.strip()]
     report: Dict[str, List[dict]] = {}
     failed = 0
@@ -224,8 +254,6 @@ def main() -> None:
         report["fused"] = legs = [r for r in results if r is not None]
         print_fused_table(legs)
 
-    # device_env() already put src/ on sys.path; repro.obs imports no jax,
-    # so the parent process never initializes a backend.
     from repro.obs import make_leg, merge_bench
     legs = [make_leg(engine=r["engine"], devices=r["devices"],
                      seed=r.get("seed", 0), mode=mode,

@@ -227,7 +227,7 @@ def check_contract(fn: Callable, args: Sequence[Any],
     jit declares ``static_argnames``) or positionally in ``args`` with
     their indices in ``static_argnums`` (when positional binding is forced,
     e.g. a jit carrying ``in_shardings``). ``x64=True`` runs the trace
-    under ``jax.experimental.enable_x64`` — required for entry points whose
+    under ``jax.enable_x64`` — required for entry points whose
     semantics are float64 by design. ``n_traces`` threads an externally
     measured trace count (see :func:`count_traces`) into the
     ``max_traces`` check.
@@ -239,8 +239,7 @@ def check_contract(fn: Callable, args: Sequence[Any],
 
     from contextlib import nullcontext
 
-    from jax.experimental import enable_x64
-    ctx = enable_x64() if x64 else nullcontext()
+    ctx = jax.enable_x64() if x64 else nullcontext()
     with ctx:
         closed = jax.make_jaxpr(
             lambda *a: jitted(*a, **kwargs),
@@ -393,10 +392,9 @@ def count_traces(fn: Callable, arg_sets: Sequence[Tuple[Sequence[Any],
 
     from contextlib import nullcontext
 
-    from jax.experimental import enable_x64
     jitted = jax.jit(fn, **jit_kwargs)
     base = int(jitted._cache_size())
-    with (enable_x64() if x64 else nullcontext()):
+    with (jax.enable_x64() if x64 else nullcontext()):
         for args, kwargs in arg_sets:
             jitted(*args, **kwargs)
     return int(jitted._cache_size()) - base

@@ -119,14 +119,14 @@ def _bank_detector_probe():
     float64 is deliberate (flag/episode agreement with the scalar
     detector is pinned bit-for-bit), and no callback may reach the
     device stream."""
+    import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     from ..analysis.contracts import CompilationContract, ContractProbe
     from .forecast_bank import DetectorBank, _detector_observe
 
     db = DetectorBank(3)
-    with enable_x64():
+    with jax.enable_x64():
         vals = jnp.zeros(db.b)
         act = jnp.ones(db.b, bool)
     contract = CompilationContract(

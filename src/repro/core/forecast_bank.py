@@ -27,7 +27,7 @@ advances **every** stream with one jitted update per sweep tick:
   unbounded lists), and coasts anomalous streams on their own prediction.
 
 Numerics: bank state is float64 (dispatches run under
-``jax.experimental.enable_x64``), so every family agrees with its scalar
+``jax.enable_x64``), so every family agrees with its scalar
 NumPy oracle to reduction-order rounding (~1e-12 relative) and the
 agreement — forecasts, binned-forecast decisions, anomaly flags — is pinned
 in ``tests/test_forecast_bank.py``. Heterogeneous AR orders / differencing
@@ -45,7 +45,6 @@ from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental import enable_x64
 
 from .. import obs
 from .anomaly import DETECTOR_ERR_WINDOW
@@ -464,7 +463,7 @@ class _FamilyBank:
         # Per-stream staging queues (plain lists: appends are the per-tick
         # hot path; the padded array is only built per flush).
         self._q: List[List[float]] = [[] for _ in range(self.b)]
-        with enable_x64():
+        with jax.enable_x64():
             self.state, self.params = self._build(list(rows))
             # Host-side snapshot of the initial state for partial resets
             # (reset_rows): self.state's device buffers are donated on every
@@ -541,7 +540,7 @@ class _FamilyBank:
         if not any(self._q):
             return 0
         n, vals = self._take_chunk()
-        with enable_x64():
+        with jax.enable_x64():
             self.state = self._chunk(self._chunk_to_device(vals))
         return n
 
@@ -550,13 +549,13 @@ class _FamilyBank:
         if not any(self._q):
             return 0, self.rollout(steps)
         n, vals = self._take_chunk()
-        with enable_x64():
+        with jax.enable_x64():
             self.state, out = self._chunk_roll(self._chunk_to_device(vals),
                                                steps)
         return n, np.asarray(out)
 
     def rollout(self, steps: int) -> np.ndarray:
-        with enable_x64():
+        with jax.enable_x64():
             out = self._roll(steps)
         return np.asarray(out)
 
@@ -572,7 +571,7 @@ class _FamilyBank:
         if len(idx) == 0:
             return
         rows = np.asarray(sorted(idx), dtype=np.int64)
-        with enable_x64():
+        with jax.enable_x64():
             take = jnp.asarray(rows)
             self.state = type(self.state)(*(
                 cur.at[take].set(jnp.asarray(init[rows]))
@@ -820,6 +819,11 @@ class ForecastBank:
     def views(self) -> List[BankedForecaster]:
         return [self.view(r) for r in range(self.n_streams)]
 
+    def device_buffers(self) -> Dict[str, object]:
+        """Every family's state arrays, by ``"<kind>.<field>"``."""
+        return {f"{kind}.{field}": arr for kind, fam in self._fams.items()
+                for field, arr in fam.state._asdict().items()}
+
     # -- updates -------------------------------------------------------------
     def stage(self, row: int, value: float) -> None:
         fam, i = self._rows[row]
@@ -985,7 +989,7 @@ def _bank_forecaster_probes():
                                       count_traces)
     from ..kernels.rls_update import rls_contract, rls_rank1_update
 
-    with enable_x64():
+    with jax.enable_x64():
         fam = _ArimaBank([dict(p=4, d=1)] * 4)
         state, params = fam.state, fam.params
         chunk = jnp.asarray(np.where(np.arange(8)[:, None] < 6,
@@ -1106,7 +1110,7 @@ class DetectorBank:
             raise ValueError("DetectorBank needs at least one stream")
         self.n = n_streams
         self.b = bucket_pow2(n_streams, minimum=1)
-        with enable_x64():
+        with jax.enable_x64():
             model = _ArimaBank([dict(p=p, d=d)] * self.b)
             self._state, self._params = model.state, model.params
             self._ring = jnp.zeros((self.b, err_window))
@@ -1126,7 +1130,7 @@ class DetectorBank:
         if len(rows) == 0:
             return
         take = np.asarray(sorted(rows), dtype=np.int64)
-        with enable_x64():
+        with jax.enable_x64():
             idx = jnp.asarray(take)
             self._state = type(self._state)(*(
                 cur.at[idx].set(jnp.asarray(init[take]))
@@ -1149,7 +1153,7 @@ class DetectorBank:
         vals[:self.n] = values
         t0 = time.perf_counter()
         with obs.timed_phase("detect", "detector.observe", streams=self.n), \
-                enable_x64():
+                jax.enable_x64():
             self._state, self._ring, self._rn, flags = _detector_observe(
                 self._state, self._params, self._ring, self._rn,
                 jnp.asarray(vals), jnp.asarray(act),

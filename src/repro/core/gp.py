@@ -27,6 +27,17 @@ from scipy import optimize as sopt
 
 _JITTER = 1e-6
 
+#: Every GP matmul asks for full float32. At the default precision a TPU
+#: multiplies float32 in one bfloat16 pass (~3 digits): the expanded
+#: squared distance below cancels, the kernel matrix loses definiteness,
+#: and fits become so sensitive that 1e-12 changes in the training data
+#: change the fitted model. CPUs compute float32 either way.
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _mm(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    return jnp.matmul(a, b, precision=HIGHEST)
+
 
 # --------------------------------------------------------------------------
 # kernel + marginal likelihood (pure functions of log-hyper-parameters)
@@ -37,7 +48,7 @@ def _matern52(x1: jnp.ndarray, x2: jnp.ndarray, ls: jnp.ndarray,
     z1 = x1 / ls
     z2 = x2 / ls
     d2 = jnp.sum(z1 * z1, -1)[:, None] + jnp.sum(z2 * z2, -1)[None, :] \
-        - 2.0 * z1 @ z2.T
+        - 2.0 * _mm(z1, z2.T)
     r = jnp.sqrt(jnp.maximum(d2, 1e-12))
     s5r = jnp.sqrt(5.0) * r
     return signal * (1.0 + s5r + 5.0 * d2 / 3.0) * jnp.exp(-s5r)
@@ -56,7 +67,7 @@ def _neg_mll(theta: jnp.ndarray, x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
     k = _matern52(x, x, ls, signal) + (noise + _JITTER) * jnp.eye(n)
     chol = jnp.linalg.cholesky(k)
     alpha = jax.scipy.linalg.cho_solve((chol, True), y)
-    mll = (-0.5 * y @ alpha
+    mll = (-0.5 * _mm(y, alpha)
            - jnp.sum(jnp.log(jnp.diagonal(chol)))
            - 0.5 * n * jnp.log(2.0 * jnp.pi))
     # Weak log-normal priors keep hyper-parameters in a sane band when n is
@@ -146,7 +157,7 @@ class GP:
         dim = self.x.shape[1]
         ls, signal, noise = _unpack(jnp.asarray(self.theta), dim)
         ks = _matern52(jnp.asarray(xq), jnp.asarray(self.x), ls, signal)
-        mean_s = ks @ jnp.asarray(self.alpha)
+        mean_s = _mm(ks, jnp.asarray(self.alpha))
         v = jax.scipy.linalg.solve_triangular(jnp.asarray(self.chol), ks.T,
                                               lower=True)
         var_s = jnp.maximum(signal - jnp.sum(v * v, axis=0), 1e-10)

@@ -33,7 +33,7 @@ import optax
 import optax.tree_utils as otu
 
 from .. import obs
-from .gp import _JITTER, GP, _matern52, _unpack, restart_inits
+from .gp import _JITTER, GP, _matern52, _mm, _unpack, restart_inits
 
 #: Default optimizer budget; mirrors ModelBank's scalar-path settings.
 DEFAULT_RESTARTS = 2
@@ -96,7 +96,7 @@ def _masked_neg_mll(theta: jnp.ndarray, x: jnp.ndarray, y: jnp.ndarray,
     chol = jnp.linalg.cholesky(k)
     alpha = jax.scipy.linalg.cho_solve((chol, True), y)
     n_real = jnp.sum(mask)
-    mll = (-0.5 * y @ alpha
+    mll = (-0.5 * _mm(y, alpha)
            - jnp.sum(jnp.log(jnp.diagonal(chol)) * mask)
            - 0.5 * n_real * jnp.log(2.0 * jnp.pi))
     # Same weak log-normal priors as the scalar path (gp._neg_mll).
@@ -120,7 +120,7 @@ def _lbfgs_minimize(fun, t0: jnp.ndarray, max_iter: int,
         count = otu.tree_get(state, "count")
         grad = otu.tree_get(state, "grad")
         return (count == 0) | ((count < max_iter)
-                               & (otu.tree_l2_norm(grad) > tol))
+                               & (otu.tree_norm(grad) > tol))
 
     def body(carry):
         t, state = carry
@@ -178,7 +178,7 @@ def _posterior_packed(x: jnp.ndarray, mask: jnp.ndarray, theta: jnp.ndarray,
         dim = xi.shape[-1]
         ls, signal, _ = _unpack(ti, dim)
         ks = _matern52(xq, xi, ls, signal) * mi[None, :]
-        mean = ks @ ai
+        mean = _mm(ks, ai)
         v = jax.scipy.linalg.solve_triangular(ci, ks.T, lower=True)
         var = jnp.maximum(signal - jnp.sum(v * v, axis=0), 1e-10)
         return mean, var

@@ -18,7 +18,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -59,8 +58,8 @@ def ring_allreduce(x: jnp.ndarray, mesh: Mesh, axis: str) -> jnp.ndarray:
         return acc.reshape(-1)[:size].reshape(shape)
 
     spec = P(axis)
-    return shard_map(inner, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                     check_rep=False)(x)
+    return jax.shard_map(inner, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                         check_vma=False)(x)
 
 
 def hierarchical_allreduce(x: jnp.ndarray, mesh: Mesh, *,
@@ -76,5 +75,5 @@ def hierarchical_allreduce(x: jnp.ndarray, mesh: Mesh, *,
         return y
 
     specs = P(*(None for _ in x.shape))
-    return shard_map(inner, mesh=mesh, in_specs=(specs,), out_specs=specs,
-                     check_rep=False)(x)
+    return jax.shard_map(inner, mesh=mesh, in_specs=(specs,),
+                         out_specs=specs, check_vma=False)(x)

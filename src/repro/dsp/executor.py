@@ -26,7 +26,7 @@ shares one implementation.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Mapping, Optional, Sequence
+from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence
 
 import numpy as np
 
@@ -43,8 +43,8 @@ def _x64():
     """Run a dispatch under float64 (the sharded engine's numerics must
     match the float64 numpy reference paths); lazy so the numpy-only
     engines never touch jax."""
-    from jax.experimental import enable_x64
-    return enable_x64()
+    import jax
+    return jax.enable_x64()
 
 #: Profiling lifecycle constants (paper §3.2).
 STABILIZATION_S = 120.0
@@ -215,6 +215,10 @@ OBSERVE_KEYS = ("rate", "latency", "usage_cpu", "usage_mem_mb")
 
 #: Telemetry window behind ``observe()`` (the paper's 1-minute window).
 OBSERVE_WINDOW_S = 60.0
+#: names of the config-derived ``[S]`` operands the device engines put on
+#: the mesh, in ``_device_configs`` order
+DEVICE_CONFIGS = ("workers", "cpu_cores", "memory_mb", "task_slots",
+                  "cap_base")
 
 
 class SweepExecutorBase:
@@ -375,6 +379,11 @@ class SweepExecutorBase:
     def caught_up(self) -> np.ndarray:
         raise NotImplementedError
 
+    def device_buffers(self) -> Dict[str, Any]:
+        """Device arrays the engine keeps across dispatches, by name (the
+        host engines keep none)."""
+        return {}
+
 
 @SIM_ENGINES.register("batched")
 class BatchedSweepExecutor(SweepExecutorBase):
@@ -502,6 +511,11 @@ class ShardedSweepExecutor(SweepExecutorBase):
                 obs.inc("transfer.h2d_bytes",
                         sum(np.asarray(a).nbytes for a in arrays))
         return self._dev_cfg
+
+    def device_buffers(self) -> Dict[str, Any]:
+        """The donated lag carry and the config operands, by name."""
+        return {"lag": self._lag,
+                **dict(zip(DEVICE_CONFIGS, self._device_configs()))}
 
     def _step_operands(self) -> tuple:
         """One full positional operand tuple for ``step_batch_arrays``

@@ -24,11 +24,13 @@ What moves on-device per interval:
   ``y = log1p(consumer_lag)``, with policy-trigger flags accumulated into a
   per-scenario counter (:attr:`FusedSweepExecutor.anomaly_triggers`) —
   auxiliary telemetry for trigger-style policies; it feeds nothing back
-  into the simulation, so all four engines stay result-equivalent. On TPU
-  the lag+detector tick is the fused Pallas kernel
-  (:mod:`repro.kernels.fused_tick`); on CPU it is the pure-jnp oracle
+  into the simulation, so all four engines stay result-equivalent. The
+  lag+detector tick is the pure-jnp oracle
   (:func:`repro.kernels.ref.fused_tick_ref`), whose lag arithmetic is
-  bit-identical to ``step_batch_arrays``.
+  bit-identical to ``step_batch_arrays``, on every backend: the carry is
+  float64 and Mosaic lowers no float64 kernel, so the fused Pallas kernel
+  (:mod:`repro.kernels.fused_tick`) is never chosen behind the caller's
+  back, and asking for it (``use_pallas=True``) is refused.
 
 Host/device split (what remains host-side, per tick but vectorized numpy):
 the downtime/checkpoint clocks and the per-row RNG streams — their update
@@ -53,13 +55,13 @@ cross-scenario collectives.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 from .. import obs
 from ..core.registry import SIM_ENGINES
-from .executor import SweepExecutorBase, _x64
+from .executor import DEVICE_CONFIGS, SweepExecutorBase, _x64
 from .simulator import (BatchedNormals, BatchState, ClusterModel, JobConfig,
                         step_batch_arrays)
 
@@ -102,8 +104,8 @@ def fused_interval_scan(model: ClusterModel, lag, det_w, det_p, det_y,
         new_lag, m = step_batch_arrays(
             model, lag_c, la, r, workers, cpu_cores, memory_mb, task_slots,
             cap_base, dpre, dpost, zz1, zz2, dt)
-        # Fused lag+detector tick: on CPU the pure-jnp oracle (its lag
-        # arithmetic is step_batch_arrays', op for op), on TPU the Pallas
+        # Fused lag+detector tick: the pure-jnp oracle (its lag arithmetic
+        # is step_batch_arrays', op for op) or, when asked for, the Pallas
         # kernel. The tick's new_lag is the authoritative carry.
         lag_k, w2, p2, err, flag = _tick(
             lag_c, la, r, m["capacity"], dpre, w, p, y_prev,
@@ -164,7 +166,13 @@ class FusedSweepExecutor(SweepExecutorBase):
 
     def __init__(self, model: ClusterModel, configs: Sequence[JobConfig],
                  seeds: Sequence[int], *, chunk: int = 16,
-                 use_pallas: Optional[bool] = None, **kwargs):
+                 use_pallas: bool = False, **kwargs):
+        if use_pallas:
+            raise ValueError(
+                "use_pallas=True needs a float32 carry: the fused engine "
+                "carries float64 state and Mosaic lowers no float64 Pallas "
+                "kernel; the float64 path is the jnp tick "
+                "(repro.kernels.ref.fused_tick_ref, use_pallas=False)")
         super().__init__(model, configs, seeds, **kwargs)
         import jax
         from jax.sharding import NamedSharding, PartitionSpec
@@ -176,8 +184,6 @@ class FusedSweepExecutor(SweepExecutorBase):
         #: tick quantum: interval lengths are padded to power-of-two
         #: multiples of this, bounding the scan's distinct trace shapes
         self.chunk = int(chunk)
-        if use_pallas is None:
-            use_pallas = jax.default_backend() == "tpu"
         self.use_pallas = bool(use_pallas)
         self.mesh = scenario_mesh(self.devices)
         self.n_devices = int(self.mesh.devices.size)
@@ -226,6 +232,14 @@ class FusedSweepExecutor(SweepExecutorBase):
                     for a in (st.workers, st.cpu_cores, st.memory_mb,
                               st.task_slots, self._cap_base))
         return self._dev_cfg
+
+    def device_buffers(self) -> Dict[str, Any]:
+        """The donated scan carry (lag + detector state) and the config
+        operands, by name."""
+        return {"lag": self._lag, "det_w": self._det_w,
+                "det_p": self._det_p, "det_y": self._det_y,
+                "det_trig": self._det_trig,
+                **dict(zip(DEVICE_CONFIGS, self._device_configs()))}
 
     def _bucket(self, K: int) -> int:
         """Padded tick count: the smallest ``chunk * 2**m >= K``."""
@@ -335,6 +349,12 @@ class FusedSweepExecutor(SweepExecutorBase):
         self.workers_hist[:, i0:i0 + K] = st.workers[:S, None]
         self.step_index += K
         return out
+
+    @property
+    def tick(self) -> str:
+        """Which tick implementation the scan runs."""
+        return ("pallas:kernels.fused_tick" if self.use_pallas
+                else "jnp:kernels.ref.fused_tick_ref")
 
     @property
     def anomaly_triggers(self) -> np.ndarray:

@@ -290,6 +290,9 @@ class SweepEngine:
 
         #: the BatchExecutor of the current/most recent run()
         self.executor: Optional[SweepExecutorBase] = None
+        #: the shared TSF bank of the most recent run() (None when no
+        #: scenario uses one)
+        self.forecast_bank: Optional[ForecastBank] = None
 
     # -- resolved config conveniences ---------------------------------------
     @property
@@ -349,7 +352,7 @@ class SweepEngine:
         forecast_bank: Optional[ForecastBank] = None
         tsf_views: Dict[int, object] = {}
         if bank_rows and config.forecast_backend == "bank":
-            forecast_bank = ForecastBank(
+            self.forecast_bank = forecast_bank = ForecastBank(
                 [self.specs[j].forecaster for j in bank_rows],
                 horizon=hp_horizon, devices=config.devices)
             tsf_views = {j: forecast_bank.view(r)

@@ -29,6 +29,7 @@ import json
 import sys
 from typing import IO, Dict, Mapping, Optional, Tuple
 
+from ..compile_cache import enable_compile_cache
 from ..core.config_space import ConfigSpace
 from ..core.executor import EngineConfig, Executor
 from ..core.registry import FLEET_BACKENDS
@@ -217,6 +218,7 @@ def main(argv: Optional[list] = None) -> int:
     ap.add_argument("--no-profiling", action="store_true",
                     help="disable the profiling process")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     api = FleetAPI(fleet=FleetConfig(capacity=args.capacity, seed=args.seed,
                                      profiling=not args.no_profiling))
     serve_jsonl(api)

@@ -32,6 +32,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from .. import obs
+from ..compile_cache import enable_compile_cache
 from ..core.config_space import paper_flink_space
 from ..core.executor import EngineConfig, ScenarioView
 from ..dsp.executor import BatchedSweepExecutor
@@ -229,6 +230,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     cfg = SoakConfig(n_jobs=args.jobs, epochs=args.epochs, seed=args.seed,
                      churn_every=args.churn_every, late_frac=args.late_frac,
                      profiling=args.profiling)
+    enable_compile_cache()
     if args.trace_out:
         obs.enable()
     result = run_soak(cfg)

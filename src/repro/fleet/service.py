@@ -147,6 +147,8 @@ class FleetController:
         self.n_deregistered = 0
         self.n_warmed = 0
         self.n_anomalies = 0
+        #: GP models fitted by the per-epoch batched refreshes
+        self.n_model_fits = 0
 
     # ------------------------------------------------------------------
     # registration churn
@@ -282,7 +284,9 @@ class FleetController:
         due_warm = [job for job in jobs
                     if job.ctl is not None and self._due(job)]
         if due_warm:
-            ModelBank.batch_refresh([job.ctl.bank for job in due_warm])
+            n_fit, _ = ModelBank.batch_refresh([job.ctl.bank
+                                                for job in due_warm])
+            self.n_model_fits += n_fit
         for job in jobs:
             if job.ctl is None:
                 self._decide_cold(
@@ -423,6 +427,10 @@ class FleetController:
             "deregistered": self.n_deregistered,
             "warmups": self.n_warmed,
             "anomalies": self.n_anomalies,
+            # batched refreshes plus live controllers' lazy fits
+            "model_fits": self.n_model_fits + sum(
+                j.ctl.bank.n_fits for j in self._jobs.values()
+                if j.ctl is not None),
             "decision_digest": self.decision_digest(),
             "ingest": {
                 "accepted": self.ingest.accepted,

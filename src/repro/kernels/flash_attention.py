@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
 
 NEG_INF = -2.0 ** 30
 LANES = 128
@@ -114,7 +113,7 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((blk_q, LANES), jnp.float32),   # denominator
             pltpu.VMEM((blk_q, d), jnp.float32),       # output acc
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr)

@@ -29,8 +29,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _fused_tick_kernel(lag_ref, add_ref, rate_ref, cap_ref, down_ref,
@@ -114,7 +113,7 @@ def fused_tick(lag: jnp.ndarray, lag_add: jnp.ndarray, rates: jnp.ndarray,
                    jax.ShapeDtypeStruct((total, k, k), dtype),
                    jax.ShapeDtypeStruct((total, 1), dtype),
                    jax.ShapeDtypeStruct((total, 1), dtype)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(lag2, add2, rate2, cap2, down2, w, P, yprev2, lam2, thresh2)

@@ -19,8 +19,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _gmm_kernel(tile_expert_ref, lhs_ref, rhs_ref, out_ref, acc_scr, *,
                 blk_k_steps: int):
@@ -71,7 +69,7 @@ def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(tile_expert.astype(jnp.int32), lhs, rhs)

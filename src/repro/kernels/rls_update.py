@@ -22,8 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _rls_kernel(p_ref, phi_ref, lam_ref, gain_ref, pnew_ref):
@@ -64,7 +63,7 @@ def rls_rank1_update(P: jnp.ndarray, phi: jnp.ndarray, lam: jnp.ndarray, *,
                    pl.BlockSpec((blk, k, k), lambda i: (i, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((total, k), P.dtype),
                    jax.ShapeDtypeStruct((total, k, k), P.dtype)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(P, phi, lam2)

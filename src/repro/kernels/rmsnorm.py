@@ -13,8 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .compat import CompilerParams
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _rmsnorm_kernel(x_ref, res_ref, scale_ref, y_ref, sum_ref, *, eps: float):
@@ -58,7 +57,7 @@ def fused_rmsnorm(x: jnp.ndarray, res: jnp.ndarray, scale: jnp.ndarray, *,
                    pl.BlockSpec((blk, d), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((total, d), x.dtype),
                    jax.ShapeDtypeStruct((total, d), x.dtype)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(xr, rr, scale)

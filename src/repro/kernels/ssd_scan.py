@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _ssd_kernel(a_ref, x_ref, dt_ref, b_ref, c_ref, y_ref, state_ref,
                 state_scr, *, chunk: int, n_heads: int):
@@ -114,7 +112,7 @@ def ssd_scan(x: jnp.ndarray, dt: jnp.ndarray, a_log: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((bsz * h, seq, p), x.dtype),
                    jax.ShapeDtypeStruct((bsz * h, p, n), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(a, xr, dtr, br, cr)

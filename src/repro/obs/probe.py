@@ -36,8 +36,6 @@ def instrumentation_probe(name: str, fn: Callable, args: Tuple,
     enabled to its obs-disabled baseline (zero added ops, no callbacks)."""
     import jax
 
-    from jax.experimental import enable_x64
-
     from ..analysis.contracts import (CompilationContract, ContractProbe,
                                       jaxpr_summary)
 
@@ -56,7 +54,7 @@ def instrumentation_probe(name: str, fn: Callable, args: Tuple,
         return len(prims)
 
     if x64:
-        with enable_x64():
+        with jax.enable_x64():
             baseline = _baseline_primitives()
     else:
         baseline = _baseline_primitives()

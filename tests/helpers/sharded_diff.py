@@ -100,23 +100,28 @@ def _specs(case: str):
     raise SystemExit(f"unknown case {case!r}")
 
 
-def _approx(a, b, rel: float, path: str = "$") -> None:
-    """Recursive JSON comparison; floats at ``rel`` relative tolerance."""
+def _approx(a, b, rel: float, path: str = "$") -> float:
+    """Recursive JSON comparison, floats at ``|a - b| <= rel * (1 + |b|)``
+    (``np.isclose`` with ``rtol = atol = rel``), everything else exactly.
+    Any mismatch raises; otherwise returns the largest float error
+    ``|a - b| / (1 + |b|)`` found, so a caller can report its margin."""
     if isinstance(a, float) and isinstance(b, float):
-        assert np.isclose(a, b, rtol=rel, atol=rel, equal_nan=True), \
-            f"{path}: {a!r} != {b!r}"
-        return
+        if a == b or (np.isnan(a) and np.isnan(b)):
+            return 0.0
+        err = abs(a - b) / (1.0 + abs(b))
+        assert err <= rel, f"{path}: {a!r} != {b!r}"
+        return err
     assert type(a) is type(b), f"{path}: {type(a)} != {type(b)}"
     if isinstance(a, dict):
         assert a.keys() == b.keys(), f"{path}: keys {a.keys()} != {b.keys()}"
-        for k in a:
-            _approx(a[k], b[k], rel, f"{path}.{k}")
-    elif isinstance(a, list):
+        return max((_approx(a[k], b[k], rel, f"{path}.{k}") for k in a),
+                   default=0.0)
+    if isinstance(a, list):
         assert len(a) == len(b), f"{path}: len {len(a)} != {len(b)}"
-        for i, (x, y) in enumerate(zip(a, b)):
-            _approx(x, y, rel, f"{path}[{i}]")
-    else:
-        assert a == b, f"{path}: {a!r} != {b!r}"
+        return max((_approx(x, y, rel, f"{path}[{i}]")
+                    for i, (x, y) in enumerate(zip(a, b))), default=0.0)
+    assert a == b, f"{path}: {a!r} != {b!r}"
+    return 0.0
 
 
 def _strip(js: dict) -> dict:

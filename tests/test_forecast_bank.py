@@ -335,13 +335,14 @@ class TestPallasKernel:
     def test_kernel_matches_ref(self, dtype):
         import contextlib
 
+        import jax
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
 
         from repro.kernels.ref import rls_rank1_update_ref
         from repro.kernels.rls_update import rls_rank1_update
 
-        ctx = enable_x64() if dtype == np.float64 else contextlib.nullcontext()
+        ctx = (jax.enable_x64() if dtype == np.float64
+               else contextlib.nullcontext())
         with ctx:
             rng = np.random.default_rng(0)
             B, k = 13, 9                     # odd batch exercises padding

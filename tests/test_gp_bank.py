@@ -86,6 +86,36 @@ class TestGPBankFit:
         assert np.all(var > 0)
         assert np.abs(mu[0] - y).max() < 0.5
 
+    def test_every_matmul_asks_for_full_float32(self):
+        """The fit and posterior jaxprs carry HIGHEST on every dot_general
+        (a TPU's default float32 matmul keeps ~3 digits)."""
+        import jax
+        import jax.numpy as jnp
+
+        from repro.core.gp_bank import _fit_packed, _posterior_packed
+
+        def dots(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "dot_general":
+                    yield eqn.params["precision"]
+                for p in eqn.params.values():
+                    for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                        inner = getattr(sub, "jaxpr", sub)
+                        if hasattr(inner, "eqns"):
+                            yield from dots(inner)
+
+        B, n, d = 2, 8, 5
+        f = lambda *s: jnp.zeros(s, jnp.float32)  # noqa: E731
+        fit = jax.make_jaxpr(lambda *a: _fit_packed(*a, max_iter=3))(
+            f(B, n, d), f(B, n), f(B, n), f(B, 2, d + 2))
+        post = jax.make_jaxpr(_posterior_packed)(
+            f(B, n, d), f(B, n), f(B, d + 2), f(B, n, n), f(B, n), f(4, d))
+        highest = (jax.lax.Precision.HIGHEST,) * 2
+        for name, jaxpr in (("fit", fit), ("posterior", post)):
+            found = list(dots(jaxpr.jaxpr))
+            assert found, name
+            assert all(p == highest for p in found), (name, found)
+
     def test_rejects_empty_and_mixed_dims(self, rng):
         with pytest.raises(ValueError, match="at least one"):
             GPBank.fit([])

@@ -161,8 +161,7 @@ class TestFusedTick:
 
     @pytest.mark.parametrize("n", [3, 8, 37])   # sub-block, exact, ragged
     def test_matches_reference(self, n):
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64():
             ops = self._operands(n, seed=n)
             got = fused_tick(**ops, lam=0.995, thresh=3.0, dt=5.0,
                              interpret=True)
@@ -186,12 +185,10 @@ class TestFusedTick:
         # dispatches, and XLA contracts multiply-adds into FMAs
         # differently per module (inside the engine's single compiled scan
         # the two expressions do agree exactly).
-        from jax.experimental import enable_x64
-
         from repro.dsp import ClusterModel
         from repro.dsp.simulator import step_batch_arrays
         n = 16
-        with enable_x64():
+        with jax.enable_x64():
             ops = self._operands(n, seed=1)
             rows = jnp.ones(n)
             new_lag, _ = step_batch_arrays(

@@ -28,6 +28,7 @@ The fused (whole-interval) engine adds interval-structure properties:
 * ``BatchState``'s host/device field classification is exhaustive and its
   host-mirror snapshot round-trips.
 """
+import jax
 import numpy as np
 import pytest
 
@@ -211,11 +212,10 @@ class TestIntervalSemantics:
     def test_scan_equals_host_driven_ticks(self, data, n, K):
         # One K-tick lax.scan == K separate step_batch_arrays dispatches
         # threading the lag by hand: same per-tick metrics, same final lag.
-        from jax.experimental import enable_x64
         (lag0, rates, lag_add, dpre, dpost, z1, z2, workers,
          cap_base) = _interval_planes(data, n, K)
         rows = np.ones(n)
-        with enable_x64():
+        with jax.enable_x64():
             carry, ms = fused_interval_scan(
                 *_scan_args(lag0, rates, lag_add, dpre, dpost, z1, z2,
                             workers, cap_base, np.ones(K, bool)),
@@ -241,11 +241,10 @@ class TestIntervalSemantics:
         # scan(2N ticks) == scan(first N) then scan(last N) with every
         # carry (lag + full detector state) threaded through — the sweep
         # engine may split an interval at any event boundary.
-        from jax.experimental import enable_x64
         (lag0, rates, lag_add, dpre, dpost, z1, z2, workers,
          cap_base) = _interval_planes(data, n, 2 * N)
         valid = np.ones(2 * N, bool)
-        with enable_x64():
+        with jax.enable_x64():
             full_c, full_m = fused_interval_scan(
                 *_scan_args(lag0, rates, lag_add, dpre, dpost, z1, z2,
                             workers, cap_base, valid), 5.0, False)

@@ -193,6 +193,17 @@ class TestFusedExecutorEquivalence:
             np.testing.assert_allclose(fu.hist[k], bat.hist[k], rtol=1e-9,
                                        atol=1e-9, err_msg=k)
 
+    def test_tick_choice_is_explicit(self):
+        # The float64 carry runs the jnp tick on every backend; asking for
+        # the Pallas tick is refused at construction (Mosaic lowers no
+        # float64 kernel), never discovered as a compile error on the chip.
+        fu = FusedSweepExecutor(MODEL, [JobConfig()], [0], dt=5.0, n_steps=4)
+        assert not fu.use_pallas
+        assert fu.tick == "jnp:kernels.ref.fused_tick_ref"
+        with pytest.raises(ValueError, match="float32 carry"):
+            FusedSweepExecutor(MODEL, [JobConfig()], [0], dt=5.0, n_steps=4,
+                               use_pallas=True)
+
     def test_interval_with_injection_mask_matches_ticked_batched(self):
         # One K-tick scan dispatch with failures marked in the [K, S] mask
         # == K batched steps with inject_failure called after the marked
