@@ -295,21 +295,23 @@ class ModelBank:
 
     # -- ensembles ----------------------------------------------------------
     def ensemble(self, segment: Segment, metric: str) -> Optional[RGPEnsemble]:
-        target_gp = self.gp(segment, metric)
-        tx, ty = segment.data(metric)
-        others = self.store.others(segment)
-        # Nearest segments first — behaviour transfers locally in rate.
-        others.sort(key=lambda s: abs(s.index - segment.index))
-        base = []
-        for seg in others:
-            g = self.gp(seg, metric)
-            if g is not None:
-                base.append(g)
-            if len(base) >= self.max_base_models:
-                break
-        return build_rgpe(target_gp, tx, ty, base,
-                          seed=segment.index * 7919 + _metric_salt(metric),
-                          devices=self.fit_devices)
+        with obs.span("demeter.ensemble", metric=metric):
+            target_gp = self.gp(segment, metric)
+            tx, ty = segment.data(metric)
+            others = self.store.others(segment)
+            # Nearest segments first — behaviour transfers locally in rate.
+            others.sort(key=lambda s: abs(s.index - segment.index))
+            base = []
+            for seg in others:
+                g = self.gp(seg, metric)
+                if g is not None:
+                    base.append(g)
+                if len(base) >= self.max_base_models:
+                    break
+            return build_rgpe(target_gp, tx, ty, base,
+                              seed=segment.index * 7919
+                              + _metric_salt(metric),
+                              devices=self.fit_devices)
 
 
 @dataclass

@@ -25,6 +25,8 @@ import jax.numpy as jnp
 import numpy as np
 from scipy import optimize as sopt
 
+from .. import obs
+
 _JITTER = 1e-6
 
 #: Every GP matmul asks for full float32. At the default precision a TPU
@@ -153,6 +155,7 @@ class GP:
     # -- posterior ---------------------------------------------------------
     def posterior(self, xq: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
         """Posterior mean and variance (original units) at (m, d) queries."""
+        obs.inc("gp.single_reads")
         xq = np.asarray(xq, np.float64).reshape(-1, self.x.shape[1])
         dim = self.x.shape[1]
         ls, signal, noise = _unpack(jnp.asarray(self.theta), dim)
@@ -178,6 +181,7 @@ class GP:
         Used by RGPE to score the target model without optimistic bias
         (Feurer et al.). Uses the closed-form LOO identities on K^-1.
         """
+        obs.inc("gp.single_reads")
         n, dim = self.x.shape
         ls, signal, noise = _unpack(jnp.asarray(self.theta), dim)
         k = _matern52(jnp.asarray(self.x), jnp.asarray(self.x), ls, signal) \

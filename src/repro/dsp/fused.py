@@ -264,52 +264,58 @@ class FusedSweepExecutor(SweepExecutorBase):
         """
         import jax
 
-        rates_ks = np.asarray(rates_ks, float)
-        K, S = rates_ks.shape
-        if S != len(self.seeds):
-            raise ValueError(f"expected {len(self.seeds)} scenario columns, "
-                             f"got {S}")
-        st = self.state
-        n = self.n_rows
-        dt = self.dt
-        Kp = self._bucket(K)
+        # Three spans tile the step: the host build of the K-tick planes,
+        # the dispatch (device_put + scan call), and the read back, which
+        # waits for the device.
+        with obs.span("engine.fused.prepare"):
+            rates_ks = np.asarray(rates_ks, float)
+            K, S = rates_ks.shape
+            if S != len(self.seeds):
+                raise ValueError(f"expected {len(self.seeds)} scenario "
+                                 f"columns, got {S}")
+            st = self.state
+            n = self.n_rows
+            dt = self.dt
+            Kp = self._bucket(K)
 
-        R = np.zeros((Kp, n))
-        R[:K, :S] = rates_ks
-        dpre = np.zeros((Kp, n), bool)
-        dpost = np.zeros((Kp, n), bool)
-        z1 = np.zeros((Kp, n))
-        z2 = np.zeros((Kp, n))
-        lag_add = np.zeros((Kp, n))
-        valid = np.zeros(Kp, bool)
-        valid[:K] = True
-        lag_add[0] = self._lag_add
-        self._lag_add = np.zeros(n)
+            R = np.zeros((Kp, n))
+            R[:K, :S] = rates_ks
+            dpre = np.zeros((Kp, n), bool)
+            dpost = np.zeros((Kp, n), bool)
+            z1 = np.zeros((Kp, n))
+            z2 = np.zeros((Kp, n))
+            lag_add = np.zeros((Kp, n))
+            valid = np.zeros(Kp, bool)
+            valid[:K] = True
+            lag_add[0] = self._lag_add
+            self._lag_add = np.zeros(n)
 
-        # Host half, precomputed for the whole interval: downtime/checkpoint
-        # clocks + RNG draws in the exact batched order (z1 all rows, then
-        # masked |z2|), with tick-k injections applied between tick k and
-        # tick k+1 — identical sequencing to the per-tick engines.
-        for k in range(K):
-            down_pre = st.downtime_left_s > 0.0
-            st.downtime_left_s = np.where(
-                down_pre, np.maximum(st.downtime_left_s - dt, 0.0),
-                st.downtime_left_s)
-            since = np.where(down_pre, st.since_checkpoint_s,
-                             st.since_checkpoint_s + dt)
-            since = np.where(~down_pre & (since >= st.checkpoint_interval_s),
-                             0.0, since)
-            st.since_checkpoint_s = since
-            down_post = st.downtime_left_s > 0.0
-            dpre[k] = down_pre
-            dpost[k] = down_post
-            z1[k] = self.rngs.draw()
-            z2[k] = np.abs(self.rngs.draw(~down_post))
-            st.last_rate = R[k]
-            if inject_ks is not None and inject_ks[k].any():
-                stage = lag_add[k + 1] if k + 1 < K else self._lag_add
-                for j in np.nonzero(inject_ks[k])[0]:
-                    self._stage_failure(int(j), stage)
+            # Host half, precomputed for the whole interval:
+            # downtime/checkpoint clocks + RNG draws in the exact batched
+            # order (z1 all rows, then masked |z2|), with tick-k injections
+            # applied between tick k and tick k+1 — identical sequencing to
+            # the per-tick engines.
+            for k in range(K):
+                down_pre = st.downtime_left_s > 0.0
+                st.downtime_left_s = np.where(
+                    down_pre, np.maximum(st.downtime_left_s - dt, 0.0),
+                    st.downtime_left_s)
+                since = np.where(down_pre, st.since_checkpoint_s,
+                                 st.since_checkpoint_s + dt)
+                since = np.where(
+                    ~down_pre & (since >= st.checkpoint_interval_s),
+                    0.0, since)
+                st.since_checkpoint_s = since
+                down_post = st.downtime_left_s > 0.0
+                dpre[k] = down_pre
+                dpost[k] = down_post
+                z1[k] = self.rngs.draw()
+                z2[k] = np.abs(self.rngs.draw(~down_post))
+                st.last_rate = R[k]
+                if inject_ks is not None and inject_ks[k].any():
+                    stage = lag_add[k + 1] if k + 1 < K else self._lag_add
+                    for j in np.nonzero(inject_ks[k])[0]:
+                        self._stage_failure(int(j), stage)
 
         with obs.timed_phase("simulate", "engine.fused.interval",
                              K=K, Kp=Kp, scenarios=S), _x64():
@@ -321,33 +327,36 @@ class FusedSweepExecutor(SweepExecutorBase):
                 self._det_y, self._det_trig, *xs, valid,
                 *self._device_configs(), DET_LAMBDA, DET_THRESH,
                 dt, self.use_pallas)
-        (self._lag, self._det_w, self._det_p, self._det_y,
-         self._det_trig) = carry
-        if obs.enabled():
-            obs.inc("sweep.intervals")
-            obs.inc("sweep.ticks", K)
-            obs.inc("sweep.scenario_ticks", K * S)
-            obs.inc("transfer.h2d_bytes",
-                    R.nbytes + lag_add.nbytes + dpre.nbytes + dpost.nbytes
-                    + z1.nbytes + z2.nbytes + valid.nbytes)
-            obs.track_jit_cache("fused_scan",
-                                int(_fused_scan()._cache_size()))
-        # Forced copy into the mirror: the device buffer is donated into
-        # the next dispatch. Valid-tick masking makes the final carry the
-        # lag after the last real tick.
-        st.from_device(self._lag)
 
-        out = {key: np.asarray(v)[:K, :S] for key, v in ms.items()}
-        if obs.enabled():
-            obs.inc("transfer.d2h_bytes",
-                    sum(v.nbytes for v in out.values())
-                    + self.state.lag_events.nbytes)
-        i0 = self.step_index + 1
-        for key in self.hist:
-            self.hist[key][:, i0:i0 + K] = out[key].T
-        # configs only change at interval boundaries -> constant workers
-        self.workers_hist[:, i0:i0 + K] = st.workers[:S, None]
-        self.step_index += K
+        with obs.span("engine.fused.readback"):
+            (self._lag, self._det_w, self._det_p, self._det_y,
+             self._det_trig) = carry
+            if obs.enabled():
+                obs.inc("sweep.intervals")
+                obs.inc("sweep.ticks", K)
+                obs.inc("sweep.scenario_ticks", K * S)
+                obs.inc("transfer.h2d_bytes",
+                        R.nbytes + lag_add.nbytes + dpre.nbytes
+                        + dpost.nbytes + z1.nbytes + z2.nbytes
+                        + valid.nbytes)
+                obs.track_jit_cache("fused_scan",
+                                    int(_fused_scan()._cache_size()))
+            # Forced copy into the mirror: the device buffer is donated
+            # into the next dispatch. Valid-tick masking makes the final
+            # carry the lag after the last real tick.
+            st.from_device(self._lag)
+
+            out = {key: np.asarray(v)[:K, :S] for key, v in ms.items()}
+            if obs.enabled():
+                obs.inc("transfer.d2h_bytes",
+                        sum(v.nbytes for v in out.values())
+                        + self.state.lag_events.nbytes)
+            i0 = self.step_index + 1
+            for key in self.hist:
+                self.hist[key][:, i0:i0 + K] = out[key].T
+            # configs only change at interval boundaries -> constant workers
+            self.workers_hist[:, i0:i0 + K] = st.workers[:S, None]
+            self.step_index += K
         return out
 
     @property

@@ -467,7 +467,8 @@ class SweepEngine:
                 # fitting — book it separately so steady-state numbers stay
                 # comparable across warm and cold processes.
                 cache0 = _gp_jit_cache_size()
-                n_fit, fit_wall = ModelBank.batch_refresh(banks)
+                with obs.span("sweep.model_refresh", banks=len(banks)):
+                    n_fit, fit_wall = ModelBank.batch_refresh(banks)
                 if _gp_jit_cache_size() > cache0:
                     model_compile_wall += fit_wall
                 else:

@@ -4,8 +4,8 @@ Host-side, opt-in observability for the sweep stack:
 
 * :mod:`repro.obs.trace` — nestable spans with monotonic ns timestamps
   and a hard zero-cost no-op path while disabled;
-* :mod:`repro.obs.metrics` — counters / gauges / fixed-bucket histograms
-  plus jit-cache recompile tracking;
+* :mod:`repro.obs.metrics` — counters and gauges plus jit-cache
+  recompile tracking;
 * :mod:`repro.obs.export` — Chrome-trace (Perfetto) JSON and the
   schema-versioned ``BENCH_sweep.json`` perf-trajectory format;
 * :mod:`repro.obs.probe` — CompilationContract probes proving the
@@ -26,17 +26,17 @@ from . import export, metrics, probe, trace
 from .export import (BENCH_SCHEMA, TRACE_SCHEMA, chrome_trace, diff_bench,
                      format_diff, leg_key, load_bench, make_bench, make_leg,
                      merge_bench, write_chrome_trace)
-from .metrics import (add_phase, inc, jit_cache_size, observe, registry,
-                      set_gauge, snapshot, track_jit_cache)
+from .metrics import (add_phase, inc, jit_cache_size, registry, set_gauge,
+                      snapshot, track_jit_cache)
 from .probe import instrumentation_probe
-from .trace import (disable, enable, enabled, enabled_scope, force_disabled,
-                    force_enabled, span, tracer)
+from .trace import (disable, enable, enabled, force_disabled, force_enabled,
+                    span, tracer)
 
 __all__ = [
     "trace", "metrics", "export", "probe",
-    "span", "tracer", "enable", "disable", "enabled", "enabled_scope",
+    "span", "tracer", "enable", "disable", "enabled",
     "force_enabled", "force_disabled",
-    "inc", "set_gauge", "observe", "add_phase", "track_jit_cache",
+    "inc", "set_gauge", "add_phase", "track_jit_cache",
     "jit_cache_size", "registry", "snapshot",
     "chrome_trace", "write_chrome_trace", "make_leg", "make_bench",
     "merge_bench", "load_bench", "diff_bench", "format_diff", "leg_key",

@@ -1,6 +1,6 @@
-"""Counters, gauges and fixed-bucket histograms for the sweep stack.
+"""Counters and gauges for the sweep stack.
 
-All mutation helpers (:func:`inc`, :func:`observe`, :func:`set_gauge`,
+All mutation helpers (:func:`inc`, :func:`set_gauge`,
 :func:`add_phase`, :func:`track_jit_cache`) are no-ops while obs is
 disabled — one module-level bool check, mirroring ``trace.span``.  The
 registry itself is always importable and inspectable so exporters and
@@ -20,13 +20,13 @@ Naming conventions (see docs/OBSERVABILITY.md):
 """
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
+from typing import Any, Dict, Optional, Sequence, Union
 
 from . import trace as _trace
 
 __all__ = [
-    "Counter", "Gauge", "Histogram", "MetricsRegistry", "registry",
-    "inc", "set_gauge", "observe", "add_phase", "track_jit_cache",
+    "Counter", "Gauge", "MetricsRegistry", "registry",
+    "inc", "set_gauge", "add_phase", "track_jit_cache",
     "jit_cache_size", "snapshot", "clear", "PHASES",
 ]
 
@@ -59,30 +59,6 @@ class Gauge:
         self.value = v
 
 
-class Histogram:
-    """Fixed-bucket histogram: ``buckets`` are inclusive upper edges; one
-    implicit overflow bucket catches everything above the last edge."""
-    __slots__ = ("name", "buckets", "counts", "total", "sum")
-
-    def __init__(self, name: str, buckets: Sequence[float]):
-        self.name = name
-        self.buckets: Tuple[float, ...] = tuple(buckets)
-        self.counts: List[int] = [0] * (len(self.buckets) + 1)
-        self.total = 0
-        self.sum = 0.0
-
-    def observe(self, v: Num) -> None:
-        i = 0
-        for i, edge in enumerate(self.buckets):
-            if v <= edge:
-                break
-        else:
-            i = len(self.buckets)
-        self.counts[i] += 1
-        self.total += 1
-        self.sum += float(v)
-
-
 class MetricsRegistry:
     def __init__(self) -> None:
         self._metrics: Dict[str, Any] = {}
@@ -99,31 +75,16 @@ class MetricsRegistry:
             m = self._metrics[name] = Gauge(name)
         return m
 
-    def histogram(self, name: str,
-                  buckets: Sequence[float]) -> Histogram:
-        m = self._metrics.get(name)
-        if m is None:
-            m = self._metrics[name] = Histogram(name, buckets)
-        return m
-
     def snapshot(self) -> Dict[str, Any]:
-        """Flat, JSON-ready view: ``{"counters": {...}, "gauges": {...},
-        "histograms": {...}}``."""
+        """Flat, JSON-ready view: ``{"counters": {...}, "gauges": {...}}``."""
         counters: Dict[str, Num] = {}
         gauges: Dict[str, Num] = {}
-        hists: Dict[str, Any] = {}
         for name, m in sorted(self._metrics.items()):
             if isinstance(m, Counter):
                 counters[name] = m.value
-            elif isinstance(m, Gauge):
-                if m.value is not None:
-                    gauges[name] = m.value
-            else:
-                hists[name] = {"buckets": list(m.buckets),
-                               "counts": list(m.counts),
-                               "total": m.total, "sum": m.sum}
-        return {"counters": counters, "gauges": gauges,
-                "histograms": hists}
+            elif m.value is not None:
+                gauges[name] = m.value
+        return {"counters": counters, "gauges": gauges}
 
     def clear(self) -> None:
         self._metrics.clear()
@@ -146,12 +107,6 @@ def set_gauge(name: str, v: Num) -> None:
     if not _trace._ENABLED:
         return
     _REGISTRY.gauge(name).set(v)
-
-
-def observe(name: str, v: Num, buckets: Sequence[float]) -> None:
-    if not _trace._ENABLED:
-        return
-    _REGISTRY.histogram(name, buckets).observe(v)
 
 
 def add_phase(phase: str, wall_s: float) -> None:

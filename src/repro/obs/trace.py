@@ -27,7 +27,7 @@ from typing import Any, Dict, List, Optional
 
 __all__ = [
     "SpanRecord", "Tracer", "tracer", "span", "enable", "disable",
-    "enabled", "enabled_scope", "force_enabled", "force_disabled",
+    "enabled", "force_enabled", "force_disabled",
 ]
 
 # Module-level flag checked on every span() call.  Kept as a plain bool
@@ -95,9 +95,12 @@ class _Span:
         return self
 
     def __exit__(self, *exc: object) -> None:
-        t1 = time.perf_counter_ns()
+        # The annotation closes first: its exit can run deferred work
+        # (jaxlib frees Python objects released off the interpreter lock)
+        # before it stamps its end, and both clocks should count that.
         if self._annot is not None:
             self._annot.__exit__(*exc)
+        t1 = time.perf_counter_ns()
         tr = self._tracer
         if tr._stack and tr._stack[-1] is self:
             tr._stack.pop()
@@ -205,11 +208,6 @@ class _EnabledScope:
     def __exit__(self, *exc: object) -> None:
         global _ENABLED
         _ENABLED = self._prev
-
-
-def enabled_scope() -> _EnabledScope:
-    """``with obs.enabled_scope(): ...`` — enable tracing for a block."""
-    return _EnabledScope(True)
 
 
 def force_enabled() -> _EnabledScope:

@@ -52,11 +52,9 @@ class TestTrace:
     def test_disabled_metrics_do_not_record(self):
         obs.inc("c", 5)
         obs.set_gauge("g", 1.0)
-        obs.observe("h", 0.5, buckets=(1.0,))
-        snap = obs.snapshot()
-        assert snap["counters"] == {}
-        assert snap["gauges"] == {}
-        assert snap["histograms"] == {}
+        obs.add_phase("simulate", 0.5)
+        obs.track_jit_cache("f", 2)
+        assert obs.snapshot() == {"counters": {}, "gauges": {}}
 
     def test_span_nesting_depths(self):
         obs.enable(clear=True)
@@ -89,7 +87,7 @@ class TestTrace:
 
     def test_enabled_scope_restores(self):
         assert not obs.enabled()
-        with obs.enabled_scope():
+        with obs.force_enabled():
             assert obs.enabled()
         assert not obs.enabled()
         obs.enable()
@@ -116,16 +114,12 @@ class TestMetrics:
         obs.inc("sweep.ticks")
         obs.inc("sweep.ticks", 4)
         obs.set_gauge("g", 2.5)
-        for v in (0.5, 1.5, 99.0):
-            obs.observe("h", v, buckets=(1.0, 10.0))
+        obs.set_gauge("g", 3.5)               # last value wins
+        obs.registry().gauge("unset")         # never set: left out
         obs.disable()
-        snap = obs.snapshot()
-        assert snap["counters"]["sweep.ticks"] == 5
-        assert snap["gauges"]["g"] == 2.5
-        h = snap["histograms"]["h"]
-        assert h["counts"] == [1, 1, 1]       # <=1, <=10, overflow
-        assert h["total"] == 3
-        assert h["sum"] == pytest.approx(101.0)
+        obs.inc("sweep.ticks")                # disabled: not counted
+        assert obs.snapshot() == {"counters": {"sweep.ticks": 5},
+                                  "gauges": {"g": 3.5}}
 
     def test_track_jit_cache_counts_growth_only(self):
         obs.enable(clear=True)
@@ -376,6 +370,180 @@ class TestSweepIntegration:
         # absolute slack absorbs scheduler noise on short walls.
         assert best_on <= best_off * 1.02 + 2e-3, \
             f"obs overhead too high: {best_off:.6f}s -> {best_on:.6f}s"
+
+
+# ---------------------------------------------------------------------------
+# the interval step's spans: they tile it, and sit on the profiler's clock
+# ---------------------------------------------------------------------------
+
+STEP_SPANS = ("engine.fused.prepare", "engine.fused.interval",
+              "engine.fused.readback")
+
+
+@pytest.fixture(scope="module")
+def clock_sweep():
+    """A cut grid, one Demeter and one baseline scenario on the fused
+    engine, run once with obs off: the engine and its result. 42 min at
+    dt 5 s reach Demeter's first profiling round (t = 2400 s at a 600 s
+    profile interval), which fits GPs and builds RGP ensembles; a failure
+    every 20 min exercises the staged injections."""
+    from repro.core import DemeterHyperParams, EngineConfig
+    from repro.dsp import PeriodicFailures, ScenarioSpec, make_trace
+    from repro.dsp.sweep import SweepEngine
+
+    trace = make_trace("diurnal", duration_s=2520.0, dt_s=5.0)
+    specs = [ScenarioSpec(trace=trace, controller=ctl, seed=seed,
+                          failures=PeriodicFailures(1200.0))
+             for ctl, seed in (("demeter", 0), ("reactive", 1))]
+    eng = SweepEngine(specs, config=EngineConfig(
+        sim_backend="fused", hp=DemeterHyperParams(profile_interval_s=600.0)))
+    obs.disable()
+    return eng, eng.run()
+
+
+class TestIntervalSpans:
+    def test_spans_tile_step_interval_and_leave_results_alone(
+            self, clock_sweep, monkeypatch):
+        """The three step spans cover 90-100 % of the time spent inside
+        ``step_interval``; what they leave is the call, the span records
+        and freeing the step's arrays on return. A pause of the garbage
+        collector between two spans counts in the call and in no span, so
+        the sweep runs three times and the best covered share is judged."""
+        from repro.dsp.fused import FusedSweepExecutor
+        sys.path.insert(0, str(REPO / "tests" / "helpers"))
+        from sharded_diff import VOLATILE
+
+        eng, off = clock_sweep
+        inside = [0]
+        step0 = FusedSweepExecutor.step_interval
+
+        def timed_step(self, rates_ks, inject_ks=None):
+            t0 = time.perf_counter_ns()
+            try:
+                return step0(self, rates_ks, inject_ks)
+            finally:
+                inside[0] += time.perf_counter_ns() - t0
+
+        def strip(js):
+            return {k: v for k, v in js.items() if k not in VOLATILE}
+
+        monkeypatch.setattr(FusedSweepExecutor, "step_interval", timed_step)
+        shares = []
+        for _ in range(3):
+            inside[0] = 0
+            obs.enable(clear=True)
+            try:
+                on = eng.run()
+            finally:
+                obs.disable()
+            assert strip(on.to_json()) == strip(off.to_json())
+            spans = obs.tracer().events
+            counts = {n: sum(s.name == n for s in spans) for n in STEP_SPANS}
+            assert len(set(counts.values())) == 1, counts
+            shares.append(sum(s.dur_ns for s in spans
+                              if s.name in STEP_SPANS) / inside[0])
+        assert off.n_model_fits > 0
+        assert {"sweep.model_refresh", "demeter.ensemble"} <= \
+            {s.name for s in spans}
+        assert obs.snapshot()["counters"]["gp.single_reads"] > 0
+        assert 0.90 <= max(shares) and max(shares) <= 1.0, shares
+
+    def test_spans_agree_with_the_profiler_clock(self, clock_sweep,
+                                                  tmp_path):
+        """Every span is also an annotation on the profiler's host plane,
+        with the same duration and a constant offset between the clocks.
+        A preemption between the two clocks' stamps of one boundary lands
+        in one clock only, so the sweep is profiled three times and each
+        span is judged by its closest reading."""
+        import jax
+        from jax.profiler import ProfileData
+
+        eng, _ = clock_sweep
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0      # as the benchmark's traced run
+        opts.host_tracer_level = 1
+        readings = []                     # per run: [(name, dur, dur', off)]
+        for run in range(3):
+            obs.enable(jax_profiler=True, clear=True)
+            out = tmp_path / str(run)
+            jax.profiler.start_trace(str(out), profiler_options=opts)
+            try:
+                eng.run()
+            finally:
+                jax.profiler.stop_trace()
+                obs.disable()
+            spans = obs.tracer().events
+            names = {s.name for s in spans}
+            assert {*STEP_SPANS, "sweep.run", "sweep.policy_block",
+                    "sweep.model_refresh", "demeter.ensemble"} <= names
+
+            (pb,) = out.glob("plugins/profile/*/*.xplane.pb")
+            host = next(p for p in ProfileData.from_file(str(pb)).planes
+                        if p.name == "/host:CPU")
+            theirs = {n: [] for n in names}
+            for line in host.lines:
+                for ev in line.events:
+                    if ev.name in theirs:
+                        theirs[ev.name].append((ev.start_ns,
+                                                ev.end_ns - ev.start_ns))
+            rows = []
+            for name in sorted(names):
+                mine = sorted((s.ts_ns, s.dur_ns) for s in spans
+                              if s.name == name)
+                assert len(theirs[name]) == len(mine), name
+                rows += [(name, d0, d1, t1 - t0) for (t0, d0), (t1, d1)
+                         in zip(mine, sorted(theirs[name]))]
+            mid = float(np.median([r[3] for r in rows]))
+            readings.append([(n, d0, d1, off - mid)
+                             for n, d0, d1, off in rows])
+        assert len({len(r) for r in readings}) == 1
+        devs = []
+        for per_run in zip(*readings):
+            assert len({r[0] for r in per_run}) == 1
+            name, d0, d1, _ = min(per_run, key=lambda r: abs(r[2] - r[1]))
+            assert abs(d1 - d0) <= max(0.02 * d1, 20_000), (name, d0, d1)
+            devs.append(min((r[3] for r in per_run), key=abs))
+        assert max(devs) - min(devs) <= 50_000
+
+
+class TestProgramNames:
+    """The benchmark's device-trace readers find programs by XLA module
+    name; a rename of either program must fail here, not turn a per-layer
+    metric silently to null."""
+
+    @staticmethod
+    def _read(metric, module_name):
+        sys.path.insert(0, str(REPO))
+        from bench import harness, tracereduce
+        event = f"{module_name}(7)"          # as the XLA Modules line
+        ctx = {"trace": {"window_s": 1.0, "programs": {
+            tracereduce.program_name(event): 0.25}},
+            "scenario_steps": 1000}
+        return harness.load_reader(metric)(ctx)
+
+    @staticmethod
+    def _module_name(lowered):
+        import re
+        return re.search(r"module @(\S+)", lowered.as_text()).group(1)
+
+    def test_fit_program_name(self):
+        import jax.numpy as jnp
+        from repro.core.gp_bank import _fit_packed
+        B, n, d, R = 2, 4, 5, 2
+        lowered = _fit_packed.lower(
+            jnp.zeros((B, n, d)), jnp.zeros((B, n)), jnp.ones((B, n)),
+            jnp.zeros((B, R, d + 2)), max_iter=3)
+        name = self._module_name(lowered)
+        assert name == "jit__fit_packed"
+        assert self._read("gp_fit_device_share.sweep", name) == 25.0
+
+    def test_interval_scan_program_name(self):
+        from repro.dsp import ClusterModel, FusedSweepExecutor, JobConfig
+        ex = FusedSweepExecutor(ClusterModel(), [JobConfig()] * 2, [0, 1],
+                                dt=5.0, n_steps=16)
+        name = self._module_name(ex.lower_interval(16))
+        assert name == "jit_fused_interval_scan"
+        assert self._read("sim_scan_ms_per_kstep", name) == 250.0
 
 
 # ---------------------------------------------------------------------------
