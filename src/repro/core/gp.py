@@ -101,6 +101,13 @@ def restart_inits(dim: int, restarts: int, seed: int) -> np.ndarray:
     return t0s
 
 
+def draw_marginals(mean: np.ndarray, var: np.ndarray, n_samples: int,
+                   rng: np.random.Generator) -> np.ndarray:
+    """Independent normal draws at each of m marginals, (n_samples, m)."""
+    return rng.normal(mean[None, :], np.sqrt(var)[None, :],
+                      size=(n_samples, len(mean)))
+
+
 @dataclass
 class GP:
     """A fitted exact GP.
@@ -164,16 +171,17 @@ class GP:
         v = jax.scipy.linalg.solve_triangular(jnp.asarray(self.chol), ks.T,
                                               lower=True)
         var_s = jnp.maximum(signal - jnp.sum(v * v, axis=0), 1e-10)
-        mean = np.asarray(mean_s) * self.y_std + self.y_mean
-        var = np.asarray(var_s) * self.y_std ** 2
-        return mean, var
+        return self.unstandardize(np.asarray(mean_s), np.asarray(var_s))
+
+    def unstandardize(self, mean_s: np.ndarray, var_s: np.ndarray
+                      ) -> Tuple[np.ndarray, np.ndarray]:
+        """Standardized posterior mean and variance in original units."""
+        return mean_s * self.y_std + self.y_mean, var_s * self.y_std ** 2
 
     def sample(self, xq: np.ndarray, n_samples: int,
                rng: np.random.Generator) -> np.ndarray:
         """Independent-marginal posterior samples, (n_samples, m)."""
-        mean, var = self.posterior(xq)
-        return rng.normal(mean[None, :], np.sqrt(var)[None, :],
-                          size=(n_samples, len(mean)))
+        return draw_marginals(*self.posterior(xq), n_samples, rng)
 
     def loo_samples(self, n_samples: int, rng: np.random.Generator) -> np.ndarray:
         """Leave-one-out posterior samples at the training points.
@@ -187,12 +195,21 @@ class GP:
         k = _matern52(jnp.asarray(self.x), jnp.asarray(self.x), ls, signal) \
             + (noise + _JITTER) * jnp.eye(n)
         kinv = np.asarray(jnp.linalg.inv(k))
+        return self.loo_draws(*self.loo_moments(np.diag(kinv)), n_samples,
+                              rng)
+
+    def loo_moments(self, kinv_diag: np.ndarray
+                    ) -> Tuple[np.ndarray, np.ndarray]:
+        """Standardized LOO mean and variance at the training points from
+        the diagonal of ``(K + (noise + jitter) I)^-1``."""
         ys = (self.chol @ self.chol.T) @ self.alpha  # K alpha = standardized y
-        diag = np.diag(kinv)
-        mu_loo = ys - self.alpha / diag
-        var_loo = np.maximum(1.0 / diag, 1e-10)
-        s = rng.normal(mu_loo[None, :], np.sqrt(var_loo)[None, :],
-                       size=(n_samples, n))
+        return (ys - self.alpha / kinv_diag,
+                np.maximum(1.0 / kinv_diag, 1e-10))
+
+    def loo_draws(self, mu_loo: np.ndarray, var_loo: np.ndarray,
+                  n_samples: int, rng: np.random.Generator) -> np.ndarray:
+        """LOO samples (original units) from :meth:`loo_moments`."""
+        s = draw_marginals(mu_loo, var_loo, n_samples, rng)
         return s * self.y_std + self.y_mean
 
     @property

@@ -80,6 +80,16 @@ def _member_layout(b: int, devices: Optional[int]):
 # --------------------------------------------------------------------------
 # masked objective (identical to gp._neg_mll on the real block)
 # --------------------------------------------------------------------------
+def _masked_kernel(x: jnp.ndarray, mask: jnp.ndarray,
+                   theta: jnp.ndarray) -> jnp.ndarray:
+    """``K + (noise + jitter) I`` over the ``mask == 1`` rows, identity on
+    the padded block: the real block is the unpadded matrix."""
+    ls, signal, noise = _unpack(theta, x.shape[-1])
+    k = _matern52(x, x, ls, signal) + (noise + _JITTER) * jnp.eye(x.shape[0])
+    m2 = mask[:, None] * mask[None, :]
+    return jnp.where(m2 > 0, k, 0.0) + jnp.diag(1.0 - mask)
+
+
 def _masked_neg_mll(theta: jnp.ndarray, x: jnp.ndarray, y: jnp.ndarray,
                     mask: jnp.ndarray) -> jnp.ndarray:
     """Negative log marginal likelihood over the ``mask == 1`` rows only.
@@ -88,11 +98,8 @@ def _masked_neg_mll(theta: jnp.ndarray, x: jnp.ndarray, y: jnp.ndarray,
     pinning their diagonal to 1, which leaves the Cholesky factor of the
     real block bit-identical to the unpadded computation.
     """
-    n, dim = x.shape
-    ls, signal, noise = _unpack(theta, dim)
-    k = _matern52(x, x, ls, signal) + (noise + _JITTER) * jnp.eye(n)
-    m2 = mask[:, None] * mask[None, :]
-    k = jnp.where(m2 > 0, k, 0.0) + jnp.diag(1.0 - mask)
+    dim = x.shape[-1]
+    k = _masked_kernel(x, mask, theta)
     chol = jnp.linalg.cholesky(k)
     alpha = jax.scipy.linalg.cho_solve((chol, True), y)
     n_real = jnp.sum(mask)
@@ -157,12 +164,7 @@ def _fit_packed(x: jnp.ndarray, y: jnp.ndarray, mask: jnp.ndarray,
                                     jnp.full(1, jnp.log(1e-2))])
         theta = jnp.where(jnp.isfinite(vs[j]), ts[j], fallback)
 
-        ls, signal, noise = _unpack(theta, dim)
-        k = _matern52(xi, xi, ls, signal) \
-            + (noise + _JITTER) * jnp.eye(xi.shape[0])
-        m2 = mi[:, None] * mi[None, :]
-        k = jnp.where(m2 > 0, k, 0.0) + jnp.diag(1.0 - mi)
-        chol = jnp.linalg.cholesky(k)
+        chol = jnp.linalg.cholesky(_masked_kernel(xi, mi, theta))
         alpha = jax.scipy.linalg.cho_solve((chol, True), yi)
         return theta, vs[j], chol, alpha
 
@@ -186,6 +188,20 @@ def _posterior_packed(x: jnp.ndarray, mask: jnp.ndarray, theta: jnp.ndarray,
     return jax.vmap(one)(x, mask, theta, chol, alpha)
 
 
+@jax.jit
+def _rgpe_reads_packed(x: jnp.ndarray, mask: jnp.ndarray, theta: jnp.ndarray,
+                       chol: jnp.ndarray, alpha: jnp.ndarray,
+                       tx: jnp.ndarray, tmask: jnp.ndarray,
+                       ttheta: jnp.ndarray):
+    """Every read of one RGPE build: the standardized posterior of B padded
+    base GPs at the target's padded training inputs ``tx`` (n, d), and the
+    diagonal of the target's masked ``(K + (noise + jitter) I)^-1`` (n,),
+    whose real block is the inverse of the unpadded matrix."""
+    mean, var = _posterior_packed(x, mask, theta, chol, alpha, tx)
+    kinv = jnp.linalg.inv(_masked_kernel(tx, tmask, ttheta))
+    return mean, var, jnp.diagonal(kinv)
+
+
 def jit_cache_size() -> int:
     """Combined dispatch-cache size of the bank's jitted entry points.
 
@@ -194,7 +210,8 @@ def jit_cache_size() -> int:
     compile wall out of steady-state fit wall, the same ``_cache_size()``
     signal ``analysis.contracts.count_traces`` measures.
     """
-    return int(_fit_packed._cache_size()) + int(_posterior_packed._cache_size())
+    return sum(int(f._cache_size()) for f in (
+        _fit_packed, _posterior_packed, _rgpe_reads_packed))
 
 
 @dataclass
@@ -327,6 +344,28 @@ class GPBank:
         return [self.member(i) for i in range(self.n_members)]
 
 
+def _pack(gps: Sequence[GP], b: int) -> Tuple[np.ndarray, ...]:
+    """Fitted GPs as zero-padded ``(x, mask, theta, chol, alpha)`` arrays
+    of ``b`` members (``b >= len(gps)``), padded to a power-of-two training
+    size with an identity block in each Cholesky factor."""
+    dim = gps[0].x.shape[1]
+    n_max = _bucket(max(len(g.alpha) for g in gps))
+    xs = np.zeros((b, n_max, dim))
+    mask = np.zeros((b, n_max))
+    theta = np.zeros((b, dim + 2))
+    chol = np.tile(np.eye(n_max), (b, 1, 1))
+    alpha = np.zeros((b, n_max))
+    for i, g in enumerate(gps):
+        n = len(g.alpha)
+        xs[i, :n] = g.x
+        mask[i, :n] = 1.0
+        theta[i] = g.theta
+        chol[i, :n, :n] = g.chol
+        chol[i, n:, :n] = 0.0
+        alpha[i, :n] = g.alpha
+    return xs, mask, theta, chol, alpha
+
+
 def batched_posterior(gps: Sequence[GP], xq: np.ndarray,
                       devices: Optional[int] = None
                       ) -> Tuple[np.ndarray, np.ndarray]:
@@ -343,28 +382,12 @@ def batched_posterior(gps: Sequence[GP], xq: np.ndarray,
         raise ValueError("batched_posterior needs at least one GP")
     dim = gps[0].x.shape[1]
     xq = np.asarray(xq, np.float64).reshape(-1, dim)
-    b = _bucket(len(gps), minimum=1)
-    b, put = _member_layout(b, devices)
-    n_max = _bucket(max(len(g.alpha) for g in gps))
-    xs = np.zeros((b, n_max, dim))
-    mask = np.zeros((b, n_max))
-    theta = np.zeros((b, dim + 2))
-    chol = np.tile(np.eye(n_max), (b, 1, 1))
-    alpha = np.zeros((b, n_max))
-    for i, g in enumerate(gps):
-        n = len(g.alpha)
-        xs[i, :n] = g.x
-        mask[i, :n] = 1.0
-        theta[i] = g.theta
-        chol[i, :n, :n] = g.chol
-        chol[i, n:, :n] = 0.0
-        alpha[i, :n] = g.alpha
+    b, put = _member_layout(_bucket(len(gps), minimum=1), devices)
     pack = put if put is not None else jnp.asarray
     with obs.span("gp_bank.batched_posterior", members=len(gps),
                   m=xq.shape[0]):
         mean_s, var_s = _posterior_packed(
-            pack(xs), pack(mask), pack(theta), pack(chol), pack(alpha),
-            jnp.asarray(xq))
+            *(pack(a) for a in _pack(gps, b)), jnp.asarray(xq))
     if obs.enabled():
         obs.track_jit_cache("gp_bank", jit_cache_size())
     y_std = np.asarray([g.y_std for g in gps])
@@ -372,3 +395,39 @@ def batched_posterior(gps: Sequence[GP], xq: np.ndarray,
     mean = np.asarray(mean_s)[:len(gps)] * y_std[:, None] + y_mean[:, None]
     var = np.asarray(var_s)[:len(gps)] * (y_std ** 2)[:, None]
     return mean, var
+
+
+def rgpe_reads(bases: Sequence[GP], target: GP,
+               devices: Optional[int] = None
+               ) -> Tuple[List[Tuple[np.ndarray, np.ndarray]],
+                          Tuple[np.ndarray, np.ndarray]]:
+    """Every GP read of one RGPE build in one jitted dispatch.
+
+    Returns each base GP's posterior mean and variance at the target's
+    training inputs, in original units as :meth:`GP.posterior` gives them,
+    and the target's standardized leave-one-out mean and variance as
+    :meth:`GP.loo_moments` gives them. The query rows (the target's
+    training size) are padded to a power of two, so target sizes share
+    programs. ``devices`` shards the base members like
+    :func:`batched_posterior`.
+    """
+    if not bases:
+        raise ValueError("rgpe_reads needs at least one base GP")
+    n, dim = target.x.shape
+    n_pad = _bucket(n)
+    tx = np.zeros((n_pad, dim))
+    tx[:n] = target.x
+    tmask = np.zeros(n_pad)
+    tmask[:n] = 1.0
+    b, put = _member_layout(_bucket(len(bases), minimum=1), devices)
+    pack = put if put is not None else jnp.asarray
+    with obs.span("gp_bank.rgpe_reads", members=len(bases), n=n):
+        out = _rgpe_reads_packed(
+            *(pack(a) for a in _pack(bases, b)), jnp.asarray(tx),
+            jnp.asarray(tmask), jnp.asarray(target.theta))
+        mean_s, var_s, kinv_diag = jax.device_get(out)
+    if obs.enabled():
+        obs.track_jit_cache("gp_bank", jit_cache_size())
+    reads = [g.unstandardize(mean_s[i, :n], var_s[i, :n])
+             for i, g in enumerate(bases)]
+    return reads, target.loo_moments(kinv_diag[:n])

@@ -17,11 +17,13 @@ optimistic bias, and weight dilution is prevented by discarding base models
 whose sampled loss exceeds the target model's 95th-percentile loss (Feurer
 et al., §4.2).
 
-Posterior evaluation is batched: with more than one active member the
-ensemble packs every member GP into stacked arrays and predicts all of them
-in a single jitted call (:func:`repro.core.gp_bank.batched_posterior`), so
-the controller's full-candidate-grid queries cost one XLA dispatch per
-metric instead of one per member.
+Every GP read is packed: the ensemble predicts all its active members in a
+single jitted call (:func:`repro.core.gp_bank.batched_posterior`), so the
+controller's full-candidate-grid queries cost one XLA dispatch per metric
+instead of one per member, and a build reads every base model's posterior
+at the target's points and the target's leave-one-out moments in one
+dispatch (:func:`repro.core.gp_bank.rgpe_reads`). The scalar reads of
+:class:`~repro.core.gp.GP` stay as the oracle they are tested against.
 """
 from __future__ import annotations
 
@@ -30,8 +32,16 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from .gp import GP
-from .gp_bank import batched_posterior
+from .. import obs
+from .gp import GP, draw_marginals
+from .gp_bank import batched_posterior, rgpe_reads
+
+
+def _count_packed_read() -> None:
+    obs.inc("gp.packed_reads")
+    # Registered at 0, so the scalar-read count reads 0 rather than nothing
+    # while every read is packed.
+    obs.inc("gp.single_reads", 0)
 
 
 def _ranking_loss(pred: np.ndarray, target: np.ndarray) -> np.ndarray:
@@ -63,13 +73,10 @@ class RGPEnsemble:
         active = [(gp, a) for gp, a in zip(self.gps, self.weights) if a > 0.0]
         if not active:
             return np.zeros(len(xq)), np.full(len(xq), 1e-12)
-        if len(active) == 1:
-            gp, a = active[0]
-            m, v = gp.posterior(xq)
-            return a * m, np.maximum((a * a) * v, 1e-12)
         # All members in one jitted dispatch, then the paper's mixture rule.
         mus, vars_ = batched_posterior([gp for gp, _ in active], xq,
                                        devices=self.devices)
+        _count_packed_read()
         w = np.asarray([a for _, a in active])
         return w @ mus, np.maximum((w * w) @ vars_, 1e-12)
 
@@ -109,15 +116,16 @@ def build_rgpe(target_gp: Optional[GP],
 
     # Score on the target GP's own training set (it may lag the segment's
     # live data by a few points when refits are batched).
-    target_x = target_gp.x
     target_y = np.asarray(target_gp.train_targets, np.float64)
     rng = np.random.default_rng(seed)
+    reads, loo_moments = rgpe_reads(base_gps, target_gp, devices=devices)
+    _count_packed_read()
 
-    losses = []  # (n_models+1, S) — target model is the last row
-    for gp in base_gps:
-        samples = gp.sample(target_x, n_samples, rng)
-        losses.append(_ranking_loss(samples, target_y))
-    loo = target_gp.loo_samples(n_samples, rng)
+    # Draws in the order of the scalar oracle (GP.sample per base model,
+    # then GP.loo_samples), so the generator is consumed identically.
+    losses = [_ranking_loss(draw_marginals(m, v, n_samples, rng), target_y)
+              for m, v in reads]  # (n_models+1, S): target is the last row
+    loo = target_gp.loo_draws(*loo_moments, n_samples, rng)
     target_loss = _ranking_loss(loo, target_y)
     losses.append(target_loss)
     loss = np.stack(losses)                       # (K+1, S)

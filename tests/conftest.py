@@ -26,6 +26,35 @@ def rng():
     return np.random.default_rng(0)
 
 
+def _factored_gp(rng, n, dim, shift=0.0):
+    """A GP at drawn hyper-parameters, factored in float64 and stored in
+    float32 as the GP bank stores its members (no fit, so no compile)."""
+    from repro.core import GP
+    x = rng.uniform(0, 1, (n, dim))
+    y = np.sin(3 * x[:, 0]) + x[:, 1] + shift + rng.normal(0, 0.1, n)
+    theta = np.concatenate([np.log(rng.uniform(0.3, 1.0, dim)),
+                            [rng.uniform(-0.5, 0.5)],
+                            [np.log(rng.uniform(1e-3, 1e-1))]])
+    ls, signal, noise = (np.exp(theta[:dim]), np.exp(theta[dim]),
+                         np.exp(theta[dim + 1]))
+    r = np.sqrt(np.sum(((x[:, None] - x[None]) / ls) ** 2, -1))
+    k = signal * (1 + np.sqrt(5) * r + 5 * r ** 2 / 3) \
+        * np.exp(-np.sqrt(5) * r) + (noise + 1e-6) * np.eye(n)
+    chol = np.linalg.cholesky(k)
+    ys = (y - y.mean()) / y.std()
+    alpha = np.linalg.solve(chol.T, np.linalg.solve(chol, ys))
+    return GP(x=x, y_mean=float(y.mean()), y_std=float(y.std()),
+              theta=theta, chol=chol.astype(np.float32),
+              alpha=alpha.astype(np.float32))
+
+
+@pytest.fixture
+def make_gp():
+    """``make_gp(rng, n, dim, shift=0.0)``: a fitted-looking GP of ``n``
+    points in ``dim`` dimensions, built without an optimizer."""
+    return _factored_gp
+
+
 def device_env(n_devices: int) -> dict:
     """An environment with ``n_devices`` virtual XLA host devices.
 

@@ -4,8 +4,31 @@ import pytest
 pytest.importorskip("hypothesis")  # property-based tests need the optional dep
 from hypothesis import given, settings, strategies as st
 
+from repro import obs
 from repro.core import (GP, LatencyConstraint, OnlineARIMA, RGPEnsemble,
                         binned_forecast, build_rgpe)
+from repro.core.gp_bank import rgpe_reads
+from repro.core.rgpe import _ranking_loss
+
+
+def _eager_rgpe_weights(target, bases, n_samples=256, seed=0):
+    """RGPE weights from the scalar oracle's reads: GP.sample per base
+    model, then GP.loo_samples, from one generator."""
+    ty = np.asarray(target.train_targets, np.float64)
+    rng = np.random.default_rng(seed)
+    losses = [_ranking_loss(g.sample(target.x, n_samples, rng), ty)
+              for g in bases]
+    target_loss = _ranking_loss(target.loo_samples(n_samples, rng), ty)
+    loss = np.stack(losses + [target_loss])
+    loss[:-1][loss[:-1] > np.percentile(target_loss, 95.0)] = np.inf
+    w = np.zeros(len(loss))
+    mins = loss.min(axis=0)
+    for col in range(loss.shape[1]):
+        winners = np.flatnonzero(loss[:, col] == mins[col])
+        w[winners] += 1.0 / len(winners)
+    w /= loss.shape[1]
+    w = np.where(w > 1e-3, w, 0.0)
+    return w / w.sum()
 
 
 class TestGP:
@@ -127,6 +150,67 @@ class TestRGPE:
         # members evaluate through the batched float32 kernel; allow f32 noise
         np.testing.assert_allclose(mu, 0.5 * m1 + 0.5 * m2, rtol=1e-5)
         np.testing.assert_allclose(var, 0.25 * v1 + 0.25 * v2, rtol=1e-5)
+
+
+class TestPackedRGPE:
+    @pytest.mark.parametrize("seed, dim, n_bases, n_target", [
+        (0, 2, 1, 3), (1, 5, 2, 4), (2, 2, 3, 5), (3, 5, 1, 7),
+        (4, 2, 2, 8), (5, 5, 3, 9), (6, 2, 1, 11), (7, 5, 2, 12),
+        (8, 2, 3, 6), (9, 5, 3, 10)])
+    def test_build_weights_equal_eager_assembly(self, make_gp, seed, dim,
+                                                n_bases, n_target):
+        rng = np.random.default_rng(seed)
+        bases = [make_gp(rng, int(rng.choice([6, 20])), dim, shift=i)
+                 for i in range(n_bases)]
+        target = make_gp(rng, n_target, dim, shift=5.0)
+        ens = build_rgpe(target, target.x, target.train_targets, bases,
+                         seed=seed)
+        want = _eager_rgpe_weights(target, bases, seed=seed)
+        np.testing.assert_allclose(ens.weights, want, rtol=0, atol=1e-12)
+
+    @pytest.mark.parametrize("n", [5, 8])     # padded to 8, and unpadded
+    def test_packed_loo_matches_closed_form(self, make_gp, n):
+        rng = np.random.default_rng(n)
+        base, target = make_gp(rng, 12, 3), make_gp(rng, n, 3, shift=2.0)
+        _, (mu, var) = rgpe_reads([base], target)
+        assert mu.shape == var.shape == (n,)
+        # the oracle's draws from the same generator state
+        got = target.loo_draws(mu, var, 64, np.random.default_rng(1))
+        want = target.loo_samples(64, np.random.default_rng(1))
+        np.testing.assert_allclose(got, want, rtol=1e-5)
+        # the closed form in float64 from the stored factor
+        ys = (target.chol.astype(np.float64) @ target.chol.T) @ target.alpha
+        kinv = np.linalg.inv(target.chol.astype(np.float64) @ target.chol.T)
+        d = np.diag(kinv)
+        np.testing.assert_allclose(var, 1.0 / d, rtol=1e-3)
+        np.testing.assert_allclose(mu, ys - target.alpha / d, rtol=1e-3,
+                                   atol=1e-3)
+
+    def test_one_member_read_matches_scalar_posterior(self, make_gp, rng):
+        g = make_gp(rng, 10, 2, shift=3.0)
+        xq = rng.uniform(0, 1, (7, 2))
+        mu, var = RGPEnsemble([g, make_gp(rng, 6, 2)],
+                              np.array([0.7, 0.0])).posterior(xq)
+        m, v = g.posterior(xq)
+        np.testing.assert_allclose(mu, 0.7 * m, rtol=1e-5)
+        np.testing.assert_allclose(var, 0.49 * v, rtol=1e-5)
+
+    def test_reads_count_packed_not_single(self, make_gp, rng):
+        bases = [make_gp(rng, 9, 2), make_gp(rng, 14, 2)]
+        target = make_gp(rng, 6, 2, shift=1.0)
+        obs.disable()
+        obs.reset()
+        obs.enable()
+        try:
+            ens = build_rgpe(target, target.x, target.train_targets, bases)
+            ens.posterior(rng.uniform(0, 1, (3, 2)))
+            RGPEnsemble([target], np.array([1.0])).posterior(target.x)
+            counters = obs.snapshot()["counters"]
+        finally:
+            obs.disable()
+            obs.reset()
+        assert counters["gp.packed_reads"] == 3
+        assert counters["gp.single_reads"] == 0
 
 
 class TestLatencyConstraint:

@@ -92,7 +92,8 @@ class TestGPBankFit:
         import jax
         import jax.numpy as jnp
 
-        from repro.core.gp_bank import _fit_packed, _posterior_packed
+        from repro.core.gp_bank import (_fit_packed, _posterior_packed,
+                                        _rgpe_reads_packed)
 
         def dots(jaxpr):
             for eqn in jaxpr.eqns:
@@ -110,11 +111,42 @@ class TestGPBankFit:
             f(B, n, d), f(B, n), f(B, n), f(B, 2, d + 2))
         post = jax.make_jaxpr(_posterior_packed)(
             f(B, n, d), f(B, n), f(B, d + 2), f(B, n, n), f(B, n), f(4, d))
+        reads = jax.make_jaxpr(_rgpe_reads_packed)(
+            f(B, n, d), f(B, n), f(B, d + 2), f(B, n, n), f(B, n), f(n, d),
+            f(n), f(d + 2))
         highest = (jax.lax.Precision.HIGHEST,) * 2
-        for name, jaxpr in (("fit", fit), ("posterior", post)):
+        for name, jaxpr in (("fit", fit), ("posterior", post),
+                            ("rgpe_reads", reads)):
             found = list(dots(jaxpr.jaxpr))
             assert found, name
             assert all(p == highest for p in found), (name, found)
+
+    def test_rgpe_reads_pad_query_rows(self, make_gp):
+        """A build's reads at the target's points, padded to a power of
+        two, equal an unpadded batched read of the same rows; target sizes
+        in one bucket share one program, counted in ``jit_cache_size``."""
+        from repro.core.gp_bank import (_posterior_packed, _rgpe_reads_packed,
+                                        jit_cache_size, rgpe_reads)
+        rng = np.random.default_rng(3)
+        dim = 7                      # a width no other test compiles
+        bases = [make_gp(rng, n, dim) for n in (6, 11, 20)]
+        cache0 = jit_cache_size()
+        own0 = _rgpe_reads_packed._cache_size()
+        post0 = _posterior_packed._cache_size()
+        for n in (9, 13):            # both padded to 16 rows
+            target = make_gp(rng, n, dim, shift=2.0)
+            reads, _ = rgpe_reads(bases, target)
+            mu_b, var_b = batched_posterior(bases, target.x)
+            for (mu, var), m_b, v_b in zip(reads, mu_b, var_b):
+                assert mu.shape == var.shape == (n,)
+                np.testing.assert_allclose(mu, m_b, rtol=1e-5, atol=1e-6)
+                np.testing.assert_allclose(var, v_b, rtol=1e-5, atol=1e-9)
+        assert _rgpe_reads_packed._cache_size() - own0 == 1
+        # plus one unpadded batched program for each of the two sizes
+        assert _posterior_packed._cache_size() - post0 == 2
+        assert jit_cache_size() - cache0 == 3
+        with pytest.raises(ValueError, match="at least one"):
+            rgpe_reads([], target)
 
     def test_rejects_empty_and_mixed_dims(self, rng):
         with pytest.raises(ValueError, match="at least one"):

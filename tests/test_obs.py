@@ -445,7 +445,10 @@ class TestIntervalSpans:
         assert off.n_model_fits > 0
         assert {"sweep.model_refresh", "demeter.ensemble"} <= \
             {s.name for s in spans}
-        assert obs.snapshot()["counters"]["gp.single_reads"] > 0
+        # every RGPE read is packed; the scalar-read counter is there at 0
+        counters = obs.snapshot()["counters"]
+        assert counters["gp.packed_reads"] > 0
+        assert counters["gp.single_reads"] == 0
         assert 0.90 <= max(shares) and max(shares) <= 1.0, shares
 
     def test_spans_agree_with_the_profiler_clock(self, clock_sweep,
